@@ -162,6 +162,15 @@ _MESH_SCRIPT = textwrap.dedent("""
 
 def _mesh_rows(rows: List[str], axis_size: int, scale: float,
                n_runs: int) -> None:
+    if jax.default_backend() != "cpu":
+        # the children's devices come from --xla_force_host_platform_device_
+        # count, which only the CPU backend honours; on an accelerator this
+        # process already holds the chips, so a child could not reach them
+        raise RuntimeError(
+            f"the collectives mesh section runs on forced CPU devices only "
+            f"(default backend here is {jax.default_backend()!r}); run the "
+            f"mesh path across chips in one process: "
+            f"python chip_smoke.py --chips 4")
     env = dict(os.environ)
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(_REPO_ROOT, "src")]

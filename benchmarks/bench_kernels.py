@@ -10,7 +10,9 @@ Two sections:
    JSON row per (op, backend, shape) so the perf trajectory can track
    backend speedups across PRs.
 
-2. **Analytic TPU v5e roofline** for each kernel configuration:
+2. **Analytic roofline** for each kernel configuration, against the peaks
+   of the device it runs on (:data:`PEAKS`, keyed by ``device_kind``; an
+   unknown device raises, so this section runs on a TPU only):
        flops  = 2 n k d (distance matmul) [+ 2 n k d accumulate for lloyd]
        bytes  = 4(nd + kd + n(out))   HBM, fused (distance matrix never stored)
        naive  = + 4 n k               HBM for the materialized matrix
@@ -31,8 +33,21 @@ from repro.core import backend as backend_mod
 from repro.core import clustering, objective
 from repro.kernels import ops, ref
 
-PEAK = 197e12
-BW = 819e9
+# (peak FLOP/s, peak HBM bytes/s) per ``jax.Device.device_kind``. TPU v5e:
+# 197 TFLOP/s bf16 and 819 GB/s HBM (Google Cloud documentation, "TPU v5e").
+PEAKS = {
+    "TPU v5 lite": (197e12, 819e9),
+}
+
+
+def device_peaks(device_kind: str):
+    """(peak FLOP/s, peak bytes/s) of a device kind; raises for a device
+    the table does not know rather than assuming another chip's peaks."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no roofline peaks for device_kind "
+                         f"{device_kind!r}; known: {sorted(PEAKS)}") from None
 
 # the chunked entrant uses a chunk *below* the sweep sizes so the lax.map
 # path actually runs (the registry default of 65536 would fall through to
@@ -151,6 +166,7 @@ def run_dispatch(out_rows: List[str] | None = None,
 
 def run_roofline(out_rows: List[str] | None = None) -> List[str]:
     rows = out_rows if out_rows is not None else []
+    PEAK, BW = device_peaks(jax.devices()[0].device_kind)
     shapes = [(4096, 64, 128), (16384, 256, 128), (65536, 50, 128)]
     for n, k, d in shapes:
         pts, ctr, w = _data(n, k, d)
@@ -166,9 +182,10 @@ def run_roofline(out_rows: List[str] | None = None) -> List[str]:
         t_naive = max(t_compute, naive_bytes / BW)
         rows.append(
             f"kernel_distance_argmin/n={n}/k={k}/d={d},{t_pal:.0f},"
-            f"ref_us={t_ref:.0f};interp_us={t_pal:.0f};"
-            f"tpu_fused_us={t_fused*1e6:.1f};tpu_naive_us={t_naive*1e6:.1f};"
-            f"tpu_speedup={t_naive/t_fused:.2f}")
+            f"ref_us={t_ref:.0f};pallas_us={t_pal:.0f};"
+            f"bound_fused_us={t_fused*1e6:.1f};"
+            f"bound_naive_us={t_naive*1e6:.1f};"
+            f"bound_speedup={t_naive/t_fused:.2f}")
         print(rows[-1], flush=True)
 
         t_ref2 = _time(jax.jit(ref.lloyd_stats_ref), pts, ctr, w)
@@ -181,9 +198,9 @@ def run_roofline(out_rows: List[str] | None = None) -> List[str]:
         tn = max(flops2 / PEAK, naive2 / BW)
         rows.append(
             f"kernel_lloyd_stats/n={n}/k={k}/d={d},{t_pal2:.0f},"
-            f"ref_us={t_ref2:.0f};interp_us={t_pal2:.0f};"
-            f"tpu_fused_us={tf*1e6:.1f};tpu_naive_us={tn*1e6:.1f};"
-            f"tpu_speedup={tn/tf:.2f}")
+            f"ref_us={t_ref2:.0f};pallas_us={t_pal2:.0f};"
+            f"bound_fused_us={tf*1e6:.1f};bound_naive_us={tn*1e6:.1f};"
+            f"bound_speedup={tn/tf:.2f}")
         print(rows[-1], flush=True)
     return rows
 
@@ -191,7 +208,10 @@ def run_roofline(out_rows: List[str] | None = None) -> List[str]:
 def run(out_rows: List[str] | None = None) -> List[str]:
     rows = out_rows if out_rows is not None else []
     run_dispatch(out_rows=rows)
-    run_roofline(out_rows=rows)
+    if jax.default_backend() == "tpu":
+        run_roofline(out_rows=rows)
+    else:
+        print("# kernel roofline: not measured (needs a TPU)", flush=True)
     return rows
 
 

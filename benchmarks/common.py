@@ -46,13 +46,20 @@ def make_graph(setting: Setting) -> Graph:
     return preferential(n, 2, seed=setting.seed)
 
 
-def load_setting(setting: Setting):
+def load_setting_host(setting: Setting):
+    """The setting's points, k, graph and padded sites, all on the host (a
+    caller that shards the sites places them itself)."""
     pts, k = paper_dataset(setting.dataset, seed=setting.seed,
                            scale=setting.scale)
     g = make_graph(setting)
     idx = partition_indices(pts, g.n, setting.partition,
                             seed=setting.seed + 1, degrees=g.degrees())
     sp, sm = pad_partition(pts, idx)
+    return pts, k, g, sp, sm
+
+
+def load_setting(setting: Setting):
+    pts, k, g, sp, sm = load_setting_host(setting)
     return pts, k, g, jnp.asarray(sp), jnp.asarray(sm)
 
 

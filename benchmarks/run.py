@@ -19,6 +19,7 @@ from benchmarks import (bench_collectives, bench_comm_scaling,
                         bench_roofline, bench_serve, bench_stream,
                         bench_topologies)
 from benchmarks.common import write_json_rows
+from repro.cache import enable_compilation_cache
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -32,6 +33,7 @@ def main(argv=None) -> None:
                          "kernels,roofline,serve,stream,topologies,faults,"
                          "frontier,collectives")
     args = ap.parse_args(argv)
+    enable_compilation_cache()
     scale = 1.0 if args.full else 0.05
     n_runs = 5 if args.full else 2
     only = set(args.only.split(",")) if args.only else None

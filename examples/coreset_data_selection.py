@@ -17,6 +17,7 @@ from repro.data import BigramLM, embed_examples, gather_selected, select_coreset
 from repro.models import init_params
 from repro.optim import adamw
 from repro.train import TrainConfig, make_train_step
+from repro.cache import enable_compilation_cache
 
 
 def train_on(batches, cfg, steps=60, seed=0):
@@ -34,6 +35,7 @@ def train_on(batches, cfg, steps=60, seed=0):
 
 
 def main():
+    enable_compilation_cache()
     cfg = configs.get_reduced("llama3_8b")
     data = BigramLM(cfg.vocab_size, seed=0)
     n_sites, per_site, L, B = 4, 128, 64, 8

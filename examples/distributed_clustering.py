@@ -15,9 +15,11 @@ from repro.core.distributed import _solve_on_coreset
 from repro.core.partition import pad_partition, partition_indices
 from repro.core.topology import bfs_spanning_tree, erdos_renyi, grid, preferential
 from repro.data.synthetic import paper_dataset
+from repro.cache import enable_compilation_cache
 
 
 def main(argv=None):
+    enable_compilation_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--dataset", default="colorhistogram")
     ap.add_argument("--scale", type=float, default=0.1)

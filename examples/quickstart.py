@@ -20,9 +20,11 @@ import numpy as np
 from repro.core import (clustering, distributed_kmeans, grid,
                         bfs_spanning_tree, distributed_kmeans_tree)
 from repro.core.partition import pad_partition, partition_indices
+from repro.cache import enable_compilation_cache
 
 
 def main(argv=None):
+    enable_compilation_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--backend", default=None,
                     help="clustering backend: jnp | jnp_chunked | pallas "

@@ -30,6 +30,7 @@ from repro.core import clustering, topology
 from repro.core.coreset import build_coreset
 from repro.data.synthetic import contaminated_stream, drifting_mixture_stream
 from repro.stream import DistributedStream, TreeConfig
+from repro.cache import enable_compilation_cache
 
 
 def offline_demo(args):
@@ -99,6 +100,7 @@ def stream_demo(args):
 
 
 def main(argv=None):
+    enable_compilation_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--backend", default=None,
                     help="clustering backend: jnp | jnp_chunked | pallas")

@@ -6,9 +6,11 @@ continuous-batching style, on any assigned architecture.
 import argparse
 
 from repro.launch.serve import main as serve_main
+from repro.cache import enable_compilation_cache
 
 
 def main(argv=None):
+    enable_compilation_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3_8b")
     args = ap.parse_args(argv)

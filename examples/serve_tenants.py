@@ -26,9 +26,11 @@ import numpy as np
 from repro.data.synthetic import drifting_mixture_stream
 from repro.serve import ClusterServeEngine, StaticCenters
 from repro.stream import ClusterQueryService, StreamState, TreeConfig
+from repro.cache import enable_compilation_cache
 
 
 def main(argv=None):
+    enable_compilation_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--backend", default=None,
                     help="clustering backend: jnp | jnp_chunked | pallas")

@@ -31,6 +31,7 @@ import numpy as np
 from repro.core import clustering
 from repro.core.coreset import distributed_coreset, staged_distributed_coreset
 from repro.core.partition import pad_partition, partition_indices
+from repro.cache import enable_compilation_cache
 
 
 def _skewed_sites(n_sites, per, d=32, k=4, seed=3):
@@ -46,6 +47,7 @@ def _skewed_sites(n_sites, per, d=32, k=4, seed=3):
 
 
 def main(argv=None):
+    enable_compilation_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--backend", default=None,
                     help="clustering backend: jnp | jnp_chunked | pallas")

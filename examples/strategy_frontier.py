@@ -22,9 +22,11 @@ import numpy as np
 from repro.core import (available_strategies, clustering,
                         graph_distributed_kmeans, wan_clusters)
 from repro.core.partition import pad_partition, partition_indices
+from repro.cache import enable_compilation_cache
 
 
 def main(argv=None):
+    enable_compilation_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--backend", default=None,
                     help="clustering backend: jnp | jnp_chunked | pallas")

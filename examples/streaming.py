@@ -28,9 +28,11 @@ from repro.core.topology import grid
 from repro.data.synthetic import drifting_mixture_stream
 from repro.stream import (ClusterQueryService, DistributedStream, StreamState,
                           TreeConfig)
+from repro.cache import enable_compilation_cache
 
 
 def main(argv=None):
+    enable_compilation_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--backend", default=None,
                     help="clustering backend: jnp | jnp_chunked | pallas")

@@ -10,9 +10,11 @@ import argparse
 import sys
 
 from repro.launch.train import main as train_main
+from repro.cache import enable_compilation_cache
 
 
 def main(argv=None):
+    enable_compilation_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--full-100m", action="store_true")
     ap.add_argument("--steps", type=int, default=200)

@@ -22,9 +22,11 @@ import numpy as np
 from repro.core.distributed import graph_distributed_kmeans
 from repro.core.partition import pad_partition, partition_indices
 from repro.core.topology import spanning_tree, wan_clusters
+from repro.cache import enable_compilation_cache
 
 
 def main(argv=None):
+    enable_compilation_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--t", type=int, default=200, help="coreset budget")
     ap.add_argument("--k", type=int, default=4)

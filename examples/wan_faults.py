@@ -27,9 +27,11 @@ from repro.data.synthetic import contaminated_stream
 from repro.stream.ingest import DistributedStream
 from repro.stream.tree import TreeConfig
 from repro.wan import FaultPlan, certify_quiescence
+from repro.cache import enable_compilation_cache
 
 
 def main():
+    enable_compilation_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--backend", default=None,
                     help="local-solve backend (e.g. pallas; interpret "

@@ -1,24 +1,12 @@
-"""Small jax version-compatibility shims.
-
-The repo targets current jax but must degrade gracefully on older
-releases (the pinned CI/container toolchain): ``shard_map`` moved out of
-``jax.experimental`` and its replication-check kwarg was renamed
-(``check_rep`` -> ``check_vma``) along the way.
-"""
+"""``shard_map`` with replication checking off."""
 from __future__ import annotations
 
 import jax
 
 
 def shard_map(f, mesh, in_specs, out_specs):
-    """``jax.shard_map`` wherever this jax provides it, with replication
-    checking off (callers here produce replicated outputs by construction,
-    e.g. the coreset solve repeated on every device, which the checker
-    cannot see through)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    """``jax.shard_map`` with ``check_vma=False``: callers here produce
+    replicated outputs by construction (e.g. the coreset solve repeated on
+    every device), which the checker cannot see through."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

@@ -109,7 +109,14 @@ def _dense_min_dist_argmin(points: Array, centers: Array
     p2 = jnp.sum(p * p, axis=-1, keepdims=True)
     c2 = jnp.sum(c * c, axis=-1)
     d2 = jnp.maximum(p2 + c2[None, :] - 2.0 * (p @ c.T), 0.0)
-    return jnp.min(d2, axis=-1), jnp.argmin(d2, axis=-1).astype(jnp.int32)
+    assign = jnp.argmin(d2, axis=-1).astype(jnp.int32)
+    # the minimum is read at the argmin, the same element jnp.min returns.
+    # A separate jnp.min is merged by XLA into argmin's (value, index)
+    # reduce; on a TPU v5e that merged reduce, fused with the vmapped
+    # distance matmul, returned NaN and inf minima for the full-scale
+    # YearPredictionMSD sites (Round-1 sensitivities, 100 x 21,280 x 50)
+    min_d2 = jnp.take_along_axis(d2, assign[:, None], axis=-1)[:, 0]
+    return min_d2, assign
 
 
 def _dense_lloyd_stats(points: Array, centers: Array,
@@ -120,9 +127,11 @@ def _dense_lloyd_stats(points: Array, centers: Array,
          else weights.astype(jnp.float32))
     min_d2, assign = _dense_min_dist_argmin(points, centers)
     k = centers.shape[0]
-    oh = jax.nn.one_hot(assign, k, dtype=jnp.float32) * w[:, None]
-    sums = oh.T @ p
-    counts = jnp.sum(oh, axis=0)
+    # segment sums, not a one-hot (k, n) @ (n, d) matmul: on a TPU an f32
+    # matmul runs as one bf16 pass at default precision; these add in f32
+    # at any precision setting
+    sums = jax.ops.segment_sum(w[:, None] * p, assign, num_segments=k)
+    counts = jax.ops.segment_sum(w, assign, num_segments=k)
     cost = jnp.sum(w * min_d2)
     return sums, counts, cost
 

@@ -33,7 +33,7 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import backend as backend_mod
 from repro.core import clustering
@@ -711,5 +711,9 @@ def spmd_distributed_kmeans(
         in_specs=(P(), P(axis_name), P(axis_name)),
         out_specs=(P(), P(axis_name), P(axis_name)),
     )
-    centers, local_costs, t_i = jax.jit(shard)(key, site_points, site_mask)
-    return centers, local_costs, t_i
+    # the per-site scalars come back replicated: on a mesh with Explicit
+    # axes (``jax.make_mesh``'s default) an axis-sharded result cannot be
+    # indexed by host code
+    replicated = NamedSharding(mesh, P())
+    return jax.jit(shard, out_shardings=replicated)(key, site_points,
+                                                    site_mask)

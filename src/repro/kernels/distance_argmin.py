@@ -25,6 +25,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.ref import F32_DOT
+
 Array = jax.Array
 
 
@@ -37,7 +39,8 @@ def _kernel(p_ref, c_ref, min_ref, arg_ref, *, block_k: int):
     c2 = jnp.sum(c * c, axis=1)                 # (bk,)
     # MXU: (bn, d) @ (d, bk)
     prod = jax.lax.dot_general(
-        p, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        p, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=F32_DOT)
     d2 = jnp.maximum(p2 + c2[None, :] - 2.0 * prod, 0.0)   # (bn, bk)
 
     local_min = jnp.min(d2, axis=1, keepdims=True)                  # (bn, 1)
@@ -67,7 +70,8 @@ def _kernel_batched(p_ref, c_ref, min_ref, arg_ref, *, block_k: int):
     p2 = jnp.sum(p * p, axis=1, keepdims=True)
     c2 = jnp.sum(c * c, axis=1)
     prod = jax.lax.dot_general(
-        p, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        p, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=F32_DOT)
     d2 = jnp.maximum(p2 + c2[None, :] - 2.0 * prod, 0.0)
 
     local_min = jnp.min(d2, axis=1, keepdims=True)
@@ -123,6 +127,7 @@ def distance_argmin_batched(points: Array, centers: Array,
             jax.ShapeDtypeStruct((T, n, 1), jnp.int32),
         ],
         interpret=interpret,
+        name="distance_argmin_batched",
     )(points, centers)
 
 
@@ -154,4 +159,5 @@ def distance_argmin(points: Array, centers: Array, block_n: int = 256,
             jax.ShapeDtypeStruct((n, 1), jnp.int32),
         ],
         interpret=interpret,
+        name="distance_argmin",
     )(points, centers)

@@ -27,6 +27,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.ref import F32_DOT
+
 Array = jax.Array
 
 
@@ -46,7 +48,8 @@ def _kernel(p_ref, c_ref, w_ref, sums_ref, counts_ref, cost_ref):
     p2 = jnp.sum(p * p, axis=1, keepdims=True)
     c2 = jnp.sum(c * c, axis=1)
     prod = jax.lax.dot_general(
-        p, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        p, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=F32_DOT)
     d2 = jnp.maximum(p2 + c2[None, :] - 2.0 * prod, 0.0)     # (bn, k)
 
     min_d2 = jnp.min(d2, axis=1, keepdims=True)              # (bn, 1)
@@ -58,7 +61,8 @@ def _kernel(p_ref, c_ref, w_ref, sums_ref, counts_ref, cost_ref):
     # MXU: (k, bn) @ (bn, d)
     sums_ref[...] += jax.lax.dot_general(
         onehot, p, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        preferred_element_type=jnp.float32,
+        precision=F32_DOT)
     counts_ref[...] += jnp.sum(onehot, axis=0, keepdims=True).T   # (k, 1)
     cost_ref[...] += jnp.sum(w * min_d2, keepdims=True)
 
@@ -92,4 +96,5 @@ def lloyd_stats(points: Array, centers: Array, weights: Array,
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="lloyd_stats",
     )(points, centers, weights)

@@ -33,6 +33,13 @@ def min_dist_argmin_ref(points: Array, centers: Array
 # backend.query_assignments_batched (DESIGN.md Sec. 13).
 CENTER_SENTINEL = 1.0e15
 
+# Precision of every dot inside the Pallas kernels. Mosaic runs an f32 dot
+# as one bf16 MXU pass unless told otherwise: on a TPU v5e that put the
+# kernels' squared distances ~1.5e-3 of |p|^2 + |c|^2 off and flipped
+# argmins at the paper's YearPredictionMSD widths. HIGHEST keeps them f32,
+# which the Weiszfeld kernel's one-hot gather needs to be exact.
+F32_DOT = jax.lax.Precision.HIGHEST
+
 
 def min_dist_argmin_batched_ref(points: Array, centers: Array
                                 ) -> Tuple[Array, Array]:
@@ -102,9 +109,9 @@ def weiszfeld_reduce(points: Array, centers: Array,
     dist = jnp.sqrt(d2 + WEISZFELD_ETA2)
     inv = jnp.maximum(w, 0.0) / dist
     k = centers.shape[0]
-    oh = jax.nn.one_hot(assign, k, dtype=jnp.float32) * inv[:, None]
-    nums = oh.T @ p
-    denoms = jnp.sum(oh, axis=0)
+    # segment sums, as in the jnp backend's Lloyd statistics (see there)
+    nums = jax.ops.segment_sum(inv[:, None] * p, assign, num_segments=k)
+    denoms = jax.ops.segment_sum(inv, assign, num_segments=k)
     cost = jnp.sum(w * jnp.sqrt(d2))
     return nums, denoms, cost
 

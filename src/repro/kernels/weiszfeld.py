@@ -47,7 +47,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.ref import WEISZFELD_ETA2
+from repro.kernels.ref import F32_DOT, WEISZFELD_ETA2
 
 Array = jax.Array
 
@@ -68,7 +68,8 @@ def _kernel(p_ref, c_ref, w_ref, nums_ref, denoms_ref, cost_ref):
     p2 = jnp.sum(p * p, axis=1, keepdims=True)
     c2 = jnp.sum(c * c, axis=1)
     prod = jax.lax.dot_general(
-        p, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        p, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=F32_DOT)
     d2 = jnp.maximum(p2 + c2[None, :] - 2.0 * prod, 0.0)     # (bn, k)
     arg = jnp.argmin(d2, axis=1).astype(jnp.int32)           # (bn,)
 
@@ -81,7 +82,8 @@ def _kernel(p_ref, c_ref, w_ref, nums_ref, denoms_ref, cost_ref):
     # then subtract -- no cancellation near zero.
     c_at = jax.lax.dot_general(
         onehot, c, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)                  # (bn, d)
+        preferred_element_type=jnp.float32,
+        precision=F32_DOT)                                   # (bn, d)
     diff = p - c_at
     min_d2 = jnp.sum(diff * diff, axis=1, keepdims=True)     # (bn, 1)
     dist = jnp.sqrt(min_d2 + WEISZFELD_ETA2)                 # (bn, 1)
@@ -91,7 +93,8 @@ def _kernel(p_ref, c_ref, w_ref, nums_ref, denoms_ref, cost_ref):
     # MXU: (k, bn) @ (bn, d)
     nums_ref[...] += jax.lax.dot_general(
         onehot, p, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        preferred_element_type=jnp.float32,
+        precision=F32_DOT)
     denoms_ref[...] += jnp.sum(onehot, axis=0, keepdims=True).T   # (k, 1)
     cost_ref[...] += jnp.sum(w * jnp.sqrt(min_d2), keepdims=True)
 
@@ -125,4 +128,5 @@ def weiszfeld_stats(points: Array, centers: Array, weights: Array,
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="weiszfeld_stats",
     )(points, centers, weights)

@@ -44,8 +44,6 @@ def run_cell(arch: str, shape_name: str, mesh_name: str, out_dir: str,
     ma = compiled.memory_analysis()
     try:
         ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):  # old jax returns [dict]
-            ca = ca[0] if ca else {}
     except Exception:
         ca = {}
     hlo_text = compiled.as_text()

@@ -1,21 +1,8 @@
 import os
-import sys
 
 # Tests run on the single real CPU device; SPMD tests spawn subprocesses with
 # their own XLA_FLAGS (the 512-device dry run must NOT leak in here).
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
-# Property tests prefer real hypothesis; fall back to the deterministic
-# seeded-sweep subset when it is not installed (see _hypothesis_fallback).
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    sys.path.insert(0, os.path.dirname(__file__))
-    from _hypothesis_fallback import build_module
-
-    _hyp = build_module()
-    sys.modules["hypothesis"] = _hyp
-    sys.modules["hypothesis.strategies"] = _hyp.strategies
 
 import gc
 

@@ -30,9 +30,7 @@ SCRIPT = textwrap.dedent("""
     cell = S.build_cell(arch, "ci", mesh, cfg_override=cfg)
     compiled = cell.lower().compile()
     ma = compiled.memory_analysis()
-    ca = compiled.cost_analysis()  # dict on new jax, [dict] on old
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
+    ca = compiled.cost_analysis()
     rep = build_report(arch, "ci", "small", cfg, kind, 64, 8, 8,
                        compiled.as_text(),
                        dict(ca or {}),

@@ -22,6 +22,7 @@ PP_SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
     from repro.compat import shard_map
+    from repro.launch.mesh import make_mesh
     from repro.train.pipeline import pipeline_forward
 
     S, M, mb, d = 4, 8, 2, 16
@@ -37,7 +38,7 @@ PP_SCRIPT = textwrap.dedent("""
     for s in range(S):
         ref = jax.vmap(lambda x: stage_fn(jnp.asarray(Ws[s]), x))(ref)
 
-    mesh = jax.make_mesh((4,), ("stage",))
+    mesh = make_mesh((4,), ("stage",))
     def run(w_all, mbs):
         return pipeline_forward(stage_fn, w_all[0], mbs, "stage", S)
 
