@@ -14,13 +14,12 @@ Two sections:
   Round 1, two in Round 2), the *measured* per-phase collective ledger
   from compiled HLO (``ppermutes_round1`` etc. via
   :func:`repro.roofline.hlo.collective_phase_analysis` -- the cross-check
-  that the schedule compiled to exactly its claimed hop count), measured
-  per-phase wall-clock (``wall_round1_us``/``wall_round2_us``: the phase's
-  gather primitives timed at the phase's exact payload shapes), end-to-end
-  wall, and a ``centers_bit_equal`` flag against the all_gather oracle.
-  On a single-core CPU host the wall columns measure dispatch+copy, not
-  ICI -- the hop columns are the hardware-relevant ranking; torus_2d's
-  (R-1)+(C-1) must be strictly below the ring's N-1 for every N >= 16.
+  that the schedule compiled to exactly its claimed hop count), the
+  end-to-end wall, and a ``centers_bit_equal`` flag against the
+  all_gather oracle. On a single-core CPU host the wall measures
+  dispatch+copy, not ICI -- the hop columns are the hardware-relevant
+  ranking; torus_2d's (R-1)+(C-1) must be strictly below the ring's N-1
+  for every N >= 16.
 
 * **Staged overlap** -- the host engine raced lockstep
   (:func:`repro.core.coreset.distributed_coreset`) vs staged
@@ -63,10 +62,7 @@ _MESH_SCRIPT = textwrap.dedent("""
     from repro.compat import shard_map
     from repro.core import spmd_distributed_kmeans
     from repro.core.distributed import spmd_distributed_kmeans_fn
-    from repro.core.message_passing import (collective_hops,
-                                            neighbor_rounds_gather,
-                                            torus_mesh_shape,
-                                            torus_rounds_gather)
+    from repro.core.message_passing import collective_hops, torus_mesh_shape
     from repro.core.partition import partition_indices, pad_partition
     from repro.roofline.hlo import collective_phase_analysis
 
@@ -84,25 +80,6 @@ _MESH_SCRIPT = textwrap.dedent("""
     t = 256
     t_buffer = max(4 * t // N, 64)
     key = jax.random.PRNGKey(0)
-
-    def phase_wall(shapes, mode, mesh_shape, reps):
-        def g(x):
-            if mode == "all_gather":
-                return jax.lax.all_gather(x, "sites")
-            if mode == "torus_2d":
-                return torus_rounds_gather(x, "sites", mesh_shape)
-            return neighbor_rounds_gather(x, "sites", N)
-        def dev(*xs):
-            return tuple(g(x[0])[None] for x in xs)
-        args = [jnp.zeros((N,) + s, jnp.float32) for s in shapes]
-        f = jax.jit(shard_map(dev, mesh=mesh,
-                              in_specs=tuple(P("sites") for _ in args),
-                              out_specs=tuple(P("sites") for _ in args)))
-        jax.block_until_ready(f(*args))
-        t0 = time.perf_counter()
-        for _ in range(reps):
-            jax.block_until_ready(f(*args))
-        return (time.perf_counter() - t0) / reps * 1e6
 
     out, oracle = [], {}
     for mode in ("all_gather", "neighbor_rounds", "torus_2d"):
@@ -128,9 +105,6 @@ _MESH_SCRIPT = textwrap.dedent("""
                           + a.dcn_collective_bytes))
         pp1, cc1, by1 = counts("round1")
         pp2, cc2, by2 = counts("round2")
-        w1 = phase_wall([()], mode, mesh_shape, reps=max(4 * n_runs, 8))
-        w2 = phase_wall([(t_buffer + k, d), (t_buffer + k,)], mode,
-                        mesh_shape, reps=max(4 * n_runs, 8))
         for objective in ("kmeans", "kmedian"):
             def run():
                 return spmd_distributed_kmeans(
@@ -152,7 +126,7 @@ _MESH_SCRIPT = textwrap.dedent("""
                 ppermutes_round1=pp1, ppermutes_round2=pp2,
                 collectives_round1=cc1, collectives_round2=cc2,
                 link_bytes_round1=by1, link_bytes_round2=by2,
-                wall_round1_us=w1, wall_round2_us=w2, e2e_us=e2e,
+                e2e_us=e2e,
                 centers_bit_equal=bool(
                     (np.asarray(c) == oracle[objective]).all()),
             ))
@@ -218,9 +192,8 @@ def _staged_rows(rows: List[str], scale: float, n_runs: int) -> None:
         jax.block_until_ready(jax.tree_util.tree_leaves(res)[0])
         t0 = time.perf_counter()
         for _ in range(n_runs):
-            res = fn()              # keep the last warm result: its
-            jax.block_until_ready(  # StagedDetail walls are compile-free
-                jax.tree_util.tree_leaves(res)[0])
+            res = fn()
+            jax.block_until_ready(jax.tree_util.tree_leaves(res)[0])
         return res, (time.perf_counter() - t0) / n_runs * 1e6
 
     def quality(dc):
@@ -256,8 +229,6 @@ def _staged_rows(rows: List[str], scale: float, n_runs: int) -> None:
             site_lengths=list(det.site_lengths),
             iters_run=[int(x) for x in np.asarray(det.iters_run)],
             t=t, lloyd_iters=lloyd_iters, **knobs,
-            wall_round1_us=det.wall_round1_s * 1e6,
-            wall_round2_us=det.wall_round2_s * 1e6,
             cost_ratio=quality(dc) / base_cost,
             bit_equal_lockstep=bit_eq,
             speedup_vs_lockstep=lock_us / us)

@@ -57,7 +57,7 @@ def _payload(n: int) -> jnp.ndarray:
 def run(scale: float = 1.0, n_runs: int = 1,
         out_rows: List[str] | None = None) -> List[str]:
     rows = out_rows if out_rows is not None else []
-    del n_runs  # wall times come from the runtime's own wall_s column
+    del n_runs  # the rows count rounds and messages, not time
 
     # -- staleness vs link-cost heterogeneity (clock mode, fault-free) ------
     for cc in CROSS_COSTS:
@@ -66,7 +66,7 @@ def run(scale: float = 1.0, n_runs: int = 1,
                                 unit_scalars=1.0)
         d = res.ledger.as_dict()
         json_row(
-            rows, f"faults/staleness/wan/cross_{cc:g}", res.wall_s * 1e6,
+            rows, f"faults/staleness/wan/cross_{cc:g}", 0.0,
             topology="wan", mode="clock", cross_cost=cc,
             n_sites=g.n, m_edges=g.m, diameter=topology.diameter(g),
             max_period=int(np.rint(cc)),
@@ -86,8 +86,7 @@ def run(scale: float = 1.0, n_runs: int = 1,
             _, res = wan_flood_exec(g, _payload(g.n), mode="full",
                                     faults=plan, unit_scalars=1.0, seed=2)
             json_row(
-                rows, f"faults/quiesce/{name}/drop_{df:g}",
-                res.wall_s * 1e6,
+                rows, f"faults/quiesce/{name}/drop_{df:g}", 0.0,
                 topology=name, mode="full", drop_frac=df,
                 edges_dropped=len(plan.drop), n_churn=len(plan.churn),
                 horizon=plan.horizon(),

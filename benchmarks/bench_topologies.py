@@ -82,19 +82,6 @@ def _ledger_match(a, b) -> bool:
                for u in LEDGER_UNITS)
 
 
-def _phase_walls(res) -> dict:
-    """Measured per-phase wall clock (us) of one executed run, from the
-    engine's ExecResult.wall_s observability column; {} for sim results
-    (the analytic path has no executed phases to time)."""
-    detail = getattr(res, "exec_detail", None)
-    if detail is None or not getattr(detail, "rounds", None):
-        return {}
-    walls = {f"wall_{name}_us": r.wall_s * 1e6
-             for name, r in detail.rounds.items()}
-    walls["wall_exec_total_us"] = sum(walls.values())
-    return walls
-
-
 def run(scale: float = 1.0, n_runs: int = 2,
         out_rows: List[str] | None = None) -> List[str]:
     rows = out_rows if out_rows is not None else []
@@ -134,7 +121,6 @@ def run(scale: float = 1.0, n_runs: int = 2,
                     centers_bit_equal=bool(np.array_equal(
                         np.asarray(res.centers),
                         np.asarray(sim_res.centers))),
-                    **_phase_walls(res),
                 )
 
         # BFS tree over the ER graph (the paper's Zhang-et-al. setting)
@@ -162,7 +148,6 @@ def run(scale: float = 1.0, n_runs: int = 2,
                 ledger_match=ledger_match,
                 centers_bit_equal=bool(np.array_equal(
                     np.asarray(res.centers), np.asarray(sim_res.centers))),
-                **_phase_walls(res),
             )
 
     # -- weighted routing payoff: min-cost vs BFS trees on WAN links --------
@@ -194,7 +179,6 @@ def run(scale: float = 1.0, n_runs: int = 2,
                 ledger_match=ledger_match,
                 centers_bit_equal=bool(np.array_equal(
                     np.asarray(res.centers), np.asarray(sim_res.centers))),
-                **_phase_walls(res),
             )
     bfs_tree = topology.bfs_spanning_tree(g)
     mst_tree = topology.mst_spanning_tree(g)

@@ -99,8 +99,6 @@ def main(argv=None):
     print(f"    site solve lengths {d_over.site_lengths}")
     print(f"    refinement passes  {list(np.asarray(d_over.iters_run))} "
           f"(cap 5)")
-    print(f"    round1 {d_over.wall_round1_s * 1e3:.1f} ms, "
-          f"round2 {d_over.wall_round2_s * 1e3:.1f} ms")
     assert int(np.asarray(over.t_i).sum()) == args.t
     print("OK")
 

@@ -169,6 +169,7 @@ def _masked_choice(key, mass):
 
 
 @functools.partial(jax.jit, static_argnames=("k", "objective", "backend"))
+@jax.named_scope("seed")
 def _kmeans_pp_init(key, points, weights, k, objective, backend):
     obj = objective_mod.get_objective(objective)
     b = backend_mod.get_backend(backend)
@@ -224,6 +225,7 @@ def lloyd(
 
 @functools.partial(jax.jit,
                    static_argnames=("iters", "objective", "k", "backend"))
+@jax.named_scope("update")
 def _lloyd(points, centers, weights, iters, objective, k, backend):
     obj = objective_mod.get_objective(objective)
     b = backend_mod.get_backend(backend)
@@ -269,6 +271,7 @@ def lloyd_converged(
 @functools.partial(jax.jit,
                    static_argnames=("iters", "tol", "objective", "k",
                                     "backend"))
+@jax.named_scope("update")
 def _lloyd_converged(points, centers, weights, iters, tol, objective, k,
                      backend):
     if tol == 0.0:

@@ -39,7 +39,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import time
 from typing import Optional, Tuple
 
 import jax
@@ -102,6 +101,7 @@ class Coreset:
                        weights=self.weights[order][:size])
 
 
+@jax.named_scope("sensitivity")
 def sensitivities(points: Array, centers: Array, weights: Array,
                   objective: ObjectiveLike = "kmeans",
                   backend: BackendLike = None
@@ -378,15 +378,10 @@ class StagedDetail:
     ``site_lengths``: the per-site padded solve lengths actually compiled
     (all equal to the lockstep pad length M unless ``site_buckets``);
     ``iters_run``: per-site realized refinement passes (== ``lloyd_iters``
-    everywhere unless ``tol > 0`` let a site exit early); the walls split
-    Round 1 (dispatch + solves until every exchange scalar is on host)
-    from Round 2 (allocation + finalize)."""
+    everywhere unless ``tol > 0`` let a site exit early)."""
 
     site_lengths: Tuple[int, ...]
     iters_run: Array
-    wall_round1_s: float
-    wall_round2_s: float
-    wall_total_s: float
 
 
 @functools.partial(
@@ -513,8 +508,8 @@ def staged_distributed_coreset(
     because the key table, draw indices, and weight formulas are shared
     term for term.
 
-    Returns ``(coreset, StagedDetail)`` -- the sidecar carries per-phase
-    walls and realized per-site lengths/iterations for
+    Returns ``(coreset, StagedDetail)`` -- the sidecar carries the
+    realized per-site lengths/iterations for
     ``bench_collectives``.
     """
     from repro.core import strategy as strategy_mod
@@ -538,7 +533,6 @@ def staged_distributed_coreset(
         t_i = strat.allocate(jnp.ones((n_sites,), site_points.dtype), t)
         t_totals = strat.sample_t_total(t, t_i)
 
-    t0 = time.perf_counter()
     solves: list = []
     pre: list = []
     final: list = []
@@ -566,10 +560,6 @@ def staged_distributed_coreset(
     dispatch_round2(n_sites - 1)
 
     local_costs = jnp.stack([s[3] for s in solves])
-    jax.block_until_ready(local_costs)
-    wall_r1 = time.perf_counter() - t0
-
-    t1 = time.perf_counter()
     if strat.needs_exchange:
         t_i = strat.allocate(local_costs, t)
         totals = jnp.broadcast_to(jnp.sum(local_costs), (n_sites,))
@@ -580,20 +570,17 @@ def staged_distributed_coreset(
                 k=k, t_buffer=t_buffer, clip_negative=clip_negative))
     points = jnp.stack([f[0] for f in final])
     weights = jnp.stack([f[1] for f in final])
-    jax.block_until_ready(weights)
-    wall_r2 = time.perf_counter() - t1
 
     detail = StagedDetail(
         site_lengths=lengths,
-        iters_run=jnp.stack([s[5] for s in solves]),
-        wall_round1_s=wall_r1, wall_round2_s=wall_r2,
-        wall_total_s=wall_r1 + wall_r2)
+        iters_run=jnp.stack([s[5] for s in solves]))
     return (DistributedCoreset(points=points, weights=weights, t_i=t_i,
                                local_costs=local_costs), detail)
 
 
 @functools.partial(
     jax.jit, static_argnames=("k", "objective", "lloyd_iters", "backend"))
+@jax.named_scope("round1")
 def round1_local_solves(keys, site_points, w_site, k, objective, lloyd_iters,
                         backend):
     """Algorithm 1 Round 1, the purely-local stage: every site solves its
@@ -629,6 +616,7 @@ def round1_local_solves(keys, site_points, w_site, k, objective, lloyd_iters,
 
 @functools.partial(
     jax.jit, static_argnames=("k", "t", "t_buffer", "clip_negative"))
+@jax.named_scope("round2")
 def round2_local_samples(keys, site_points, m, w_eff, assign, centers, t_i,
                          total_m, k, t, t_buffer, clip_negative):
     """Algorithm 1 Round 2, the purely-local stage: every site draws its
@@ -653,6 +641,7 @@ def round2_local_samples(keys, site_points, m, w_eff, assign, centers, t_i,
 
 @functools.partial(
     jax.jit, static_argnames=("k", "t_buffer", "clip_negative"))
+@jax.named_scope("round2")
 def round2_local_samples_localized(keys, site_points, m, w_eff, assign,
                                    centers, t_i, total_m, k, t_buffer,
                                    clip_negative):

@@ -95,12 +95,16 @@ class ClusteringResult:
 
 def _solve_on_coreset(key: Array, cs: Coreset, k: int, objective: str,
                       lloyd_iters: int, backend: BackendLike = None) -> Array:
-    centers = clustering.kmeans_pp_init(key, cs.points, k,
-                                        weights=jnp.maximum(cs.weights, 0.0),
-                                        objective=objective, backend=backend)
-    centers, _ = clustering.lloyd(cs.points, centers, weights=cs.weights,
-                                  iters=lloyd_iters, objective=objective,
-                                  backend=backend)
+    """Seed and update k centers on the gathered coreset; the host span
+    ``final_solve`` carries the coreset buffer's rows."""
+    with jax.profiler.TraceAnnotation("final_solve",
+                                      rows=cs.points.shape[0], k=k):
+        centers = clustering.kmeans_pp_init(
+            key, cs.points, k, weights=jnp.maximum(cs.weights, 0.0),
+            objective=objective, backend=backend)
+        centers, _ = clustering.lloyd(cs.points, centers, weights=cs.weights,
+                                      iters=lloyd_iters, objective=objective,
+                                      backend=backend)
     return centers
 
 

@@ -37,7 +37,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import time
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
@@ -119,19 +118,12 @@ class ExecResult:
     extra round so the final fresh messages are forwarded, which is what
     makes the measured transmission count equal the analytic 2mn).
     ``ledger`` is *measured*: every scalar/point/message was counted from an
-    actual executed transmission, never from a formula.
-
-    ``wall_s`` is the host wall-clock time the primitive spent (schedule
-    execution + ledger pricing, excluding schedule compilation, which is
-    cached per graph). It feeds the per-phase timing columns of
-    ``bench_topologies`` -- an observability column, deliberately excluded
-    from every ledger-parity identity."""
+    actual executed transmission, never from a formula."""
 
     rounds: int
     rounds_to_complete: int
     ledger: CommLedger
     per_round_transmissions: List[int]
-    wall_s: float = 0.0
 
 
 def pack_payload(points: jax.Array, weights: jax.Array) -> jax.Array:
@@ -304,7 +296,6 @@ def flood_exec(schedule: Union[GossipSchedule, Graph], payload: jax.Array,
     if payload.shape[0] != schedule.n:
         raise ValueError(f"payload must be origin-indexed: got leading dim "
                          f"{payload.shape[0]} for a {schedule.n}-node graph")
-    t0 = time.perf_counter()
     trailing = payload.shape[1:]
     flat = payload.reshape(schedule.n, -1)
     table, known, sends, fwd, complete = _flood_exec_rounds(
@@ -333,8 +324,7 @@ def flood_exec(schedule: Union[GossipSchedule, Graph], payload: jax.Array,
     res = ExecResult(rounds=schedule.n_rounds, rounds_to_complete=done,
                      ledger=ledger,
                      per_round_transmissions=[int(s) for s in
-                                              np.asarray(sends)],
-                     wall_s=time.perf_counter() - t0)
+                                              np.asarray(sends)])
     return table.reshape((schedule.n, schedule.n) + trailing), res
 
 
@@ -450,7 +440,6 @@ def tree_gather_exec(schedule: TreeSchedule, payload: jax.Array,
     if payload.shape[0] != schedule.n:
         raise ValueError(f"payload must be origin-indexed: got leading dim "
                          f"{payload.shape[0]} for a {schedule.n}-node tree")
-    t0 = time.perf_counter()
     trailing = payload.shape[1:]
     flat = payload.reshape(schedule.n, -1)
 
@@ -481,8 +470,7 @@ def tree_gather_exec(schedule: TreeSchedule, payload: jax.Array,
                      rounds_to_complete=schedule.height, ledger=ledger,
                      per_round_transmissions=[int(x) for x in
                                               np.asarray(hops.sum(axis=1))]
-                     if schedule.height else [],
-                     wall_s=time.perf_counter() - t0)
+                     if schedule.height else [])
     return table[schedule.root].reshape((schedule.n,) + trailing), res
 
 
@@ -499,7 +487,6 @@ def tree_scatter_exec(schedule: TreeSchedule, root_values: jax.Array,
         raise ValueError(f"root_values must be origin-indexed: got leading "
                          f"dim {root_values.shape[0]} for a {schedule.n}-"
                          f"node tree")
-    t0 = time.perf_counter()
     trailing = root_values.shape[1:]
     flat = root_values.reshape(schedule.n, -1)
     n = schedule.n
@@ -529,8 +516,7 @@ def tree_scatter_exec(schedule: TreeSchedule, root_values: jax.Array,
                      rounds_to_complete=schedule.height, ledger=ledger,
                      per_round_transmissions=[int(x) for x in
                                               np.asarray(hops.sum(axis=1))]
-                     if schedule.height else [],
-                     wall_s=time.perf_counter() - t0)
+                     if schedule.height else [])
     return own.reshape((n,) + trailing), res
 
 
@@ -552,7 +538,6 @@ def tree_up_sum_exec(schedule: TreeSchedule, values: jax.Array,
     if values.shape[0] != schedule.n:
         raise ValueError(f"values must be node-indexed: got leading dim "
                          f"{values.shape[0]} for a {schedule.n}-node tree")
-    t0 = time.perf_counter()
     trailing = values.shape[1:]
     flat = values.reshape(schedule.n, -1)
 
@@ -587,8 +572,7 @@ def tree_up_sum_exec(schedule: TreeSchedule, values: jax.Array,
                            per_origin_link=np.asarray([w_sends], np.float64))
     res = ExecResult(rounds=schedule.height * (2 if broadcast else 1),
                      rounds_to_complete=schedule.height, ledger=ledger,
-                     per_round_transmissions=per_round,
-                     wall_s=time.perf_counter() - t0)
+                     per_round_transmissions=per_round)
     return out.reshape((schedule.n,) + trailing), res
 
 
@@ -599,7 +583,6 @@ def tree_broadcast_exec(schedule: TreeSchedule, value: jax.Array,
     transmissions). Returns every node's (bit-identical) copy ``(n, ...)``
     and the measured ledger (equals ``tree_broadcast_cost``)."""
     value = jnp.asarray(value)
-    t0 = time.perf_counter()
     flat = value.reshape(-1)
     vals0 = jnp.zeros((schedule.n, flat.shape[0]), flat.dtype).at[
         schedule.root].set(flat)
@@ -621,8 +604,7 @@ def tree_broadcast_exec(schedule: TreeSchedule, value: jax.Array,
                      rounds_to_complete=schedule.height, ledger=ledger,
                      per_round_transmissions=[int(x) for x in
                                               np.asarray(sends)]
-                     if schedule.height else [],
-                     wall_s=time.perf_counter() - t0)
+                     if schedule.height else [])
     return vals.reshape((schedule.n,) + value.shape), res
 
 

@@ -183,6 +183,7 @@ def _cohen_addad_local_summary(strat, keys, site_points, w_site, *, k,
 
 
 @functools.partial(jax.jit, static_argnames=("k",))
+@jax.named_scope("sensitivity")
 def _refine_batch(m, assign, w_eff, k):
     return jax.vmap(lambda mi, ai, wi: _refined_sensitivities(mi, ai, wi, k)
                     )(m, assign, w_eff)
@@ -306,11 +307,15 @@ class CoresetStrategy:
     def summary(self, keys: Array, site_points: Array, w_site: Array, *,
                 k: int, objective: str, lloyd_iters: int,
                 backend: str) -> Round1State:
-        """Round 1's local stage over all sites."""
-        return self.local_summary_fn(self, keys, site_points, w_site, k=k,
-                                     objective=objective,
-                                     lloyd_iters=lloyd_iters,
-                                     backend=backend)
+        """Round 1's local stage over all sites. The host span ``round1``
+        carries the rows the stage sweeps (every site padded to ``M``)."""
+        n_sites, m, d = site_points.shape
+        with jax.profiler.TraceAnnotation("round1", sites=n_sites,
+                                          rows=n_sites * m, d=d, k=k):
+            return self.local_summary_fn(self, keys, site_points, w_site,
+                                         k=k, objective=objective,
+                                         lloyd_iters=lloyd_iters,
+                                         backend=backend)
 
     def exchange_spec(self) -> Optional[ExchangeSpec]:
         """The declared Round-1 communication shape (``None`` == no
@@ -323,16 +328,21 @@ class CoresetStrategy:
 
     def allocate(self, costs: Array, t: int) -> Array:
         """Split the budget: ``sum == t`` exactly, every strategy."""
-        return self.allocate_fn(self, costs, t)
+        with jax.profiler.TraceAnnotation("allocate"):
+            return self.allocate_fn(self, costs, t)
 
     def contribute(self, keys: Array, site_points: Array, r1: Round1State,
                    t_i: Array, totals: Array, *, k: int, t: int,
                    t_buffer: int, clip_negative: bool):
         """Round 2's local stage: batched per-site portions (a vmapped
-        :class:`~repro.core.coreset.Coreset`)."""
-        return self.local_contribution_fn(
-            self, keys, site_points, r1, t_i, totals, k=k, t=t,
-            t_buffer=t_buffer, clip_negative=clip_negative)
+        :class:`~repro.core.coreset.Coreset`). The host span ``round2``
+        carries the rows the stage samples from."""
+        n_sites, m = site_points.shape[:2]
+        with jax.profiler.TraceAnnotation("round2", sites=n_sites,
+                                          rows=n_sites * m):
+            return self.local_contribution_fn(
+                self, keys, site_points, r1, t_i, totals, k=k, t=t,
+                t_buffer=t_buffer, clip_negative=clip_negative)
 
     def assemble(self, points: Array, weights: Array):
         """Stitch moved portions into one flat coreset."""

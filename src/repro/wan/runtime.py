@@ -38,7 +38,6 @@ host oracle run on the surviving sites alone.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Dict, List, Optional, Tuple, Union
 
 import jax
@@ -82,7 +81,6 @@ class WanExecResult:
     staleness: np.ndarray
     known: np.ndarray
     mode: str
-    wall_s: float = 0.0
 
 
 @jax.jit
@@ -160,7 +158,6 @@ def wan_flood_exec(graph: Graph, payload: Array, mode: str = "full",
     spread before death."""
     plan = faults if faults is not None else FaultPlan()
     ws = wan_schedule(graph)
-    t0 = time.perf_counter()
     payload = jnp.asarray(payload)
     if payload.shape[0] != graph.n:
         raise ValueError(f"payload must be origin-indexed: got leading dim "
@@ -257,8 +254,7 @@ def wan_flood_exec(graph: Graph, payload: Array, mode: str = "full",
                         rounds_to_quiesce=rounds_to_quiesce,
                         ledger=ledger, per_round_transmissions=per_round_tx,
                         completion=completion, staleness=staleness,
-                        known=known_np, mode=mode,
-                        wall_s=time.perf_counter() - t0)
+                        known=known_np, mode=mode)
     return table.reshape((graph.n, graph.n) + trailing), res
 
 

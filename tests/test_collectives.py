@@ -295,7 +295,6 @@ def test_staged_strict_bit_parity(strategy, objective):
         assert (a == b).all(), f"{strategy}/{objective}: {f} differs"
     assert detail.site_lengths == (sp.shape[1],) * sp.shape[0]
     assert (np.asarray(detail.iters_run) == 5).all()  # lockstep iter count
-    assert detail.wall_round1_s > 0 and detail.wall_round2_s > 0
 
 
 def test_staged_overlap_mode_deterministic_and_valid():
