@@ -89,16 +89,23 @@ class Coreset:
             points=jnp.concatenate([c.points for c in coresets], axis=-2),
             weights=jnp.concatenate([c.weights for c in coresets], axis=-1))
 
+    @functools.partial(jax.jit, static_argnums=1)
     def compact(self, size: Optional[int] = None) -> "Coreset":
         """Move weight-carrying slots to the front (stable) and truncate to
-        ``size`` slots (default: same size). Mask-aware and jit-able (static
-        output shape). Caller contract: ``size`` must be >= the number of
-        nonzero-weight slots, otherwise mass is silently dropped -- check
-        ``effective_size()`` first when in doubt."""
+        ``size`` slots (default: same size); slots past the nonzero count
+        carry weight 0. An index from a cumsum and one gather of ``size``
+        rows: no sort over the buffer. Caller contract: ``size`` must be
+        >= the number of nonzero-weight slots, otherwise mass is silently
+        dropped -- check ``effective_size()`` first when in doubt."""
         size = self.size if size is None else size
-        order = jnp.argsort(self.weights == 0.0, stable=True)
-        return Coreset(points=self.points[order][:size],
-                       weights=self.weights[order][:size])
+        nonzero = jnp.cumsum(self.weights != 0.0, dtype=jnp.int32)
+        slot = jnp.arange(size, dtype=jnp.int32)
+        # row of the (slot+1)-th nonzero weight; past the last, out of range
+        idx = jnp.minimum(jnp.searchsorted(nonzero, slot + 1, side="left"),
+                          self.size - 1)
+        return Coreset(points=self.points[idx],
+                       weights=jnp.where(slot < nonzero[-1],
+                                         self.weights[idx], 0.0))
 
 
 @jax.named_scope("sensitivity")
@@ -300,6 +307,16 @@ class DistributedCoreset:
         d = self.points.shape[-1]
         return Coreset(points=self.points.reshape(-1, d),
                        weights=self.weights.reshape(-1))
+
+
+def gathered_live_rows(rows: int, t: int, k: int) -> int:
+    """Most rows of a gathered ``rows``-row buffer that can carry weight.
+
+    The buffer is the sites' portions of ``t_buffer = t`` sample slots
+    then ``k`` centers, and the portions' valid samples number ``sum t_i
+    == t`` (``proportional_allocation``'s largest remainders), so at most
+    ``t + portions * k`` rows are nonzero. Static: from shapes alone."""
+    return t + (rows // (t + k)) * k
 
 
 def distributed_coreset(

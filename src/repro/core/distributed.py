@@ -46,7 +46,8 @@ from repro.core.comm import (CommLedger, flood_cost, flood_portions_cost,
                              tree_allocation_cost, tree_broadcast_cost,
                              tree_up_cost)
 from repro.core.coreset import (Coreset, DistributedCoreset,
-                                distributed_coreset, proportional_allocation,
+                                distributed_coreset, gathered_live_rows,
+                                proportional_allocation,
                                 round1_local_solves, round2_local_samples,
                                 sensitivities, _sample_and_weight)
 from repro.core.message_passing import (ExecResult, GossipSchedule,
@@ -94,9 +95,19 @@ class ClusteringResult:
 
 
 def _solve_on_coreset(key: Array, cs: Coreset, k: int, objective: str,
-                      lloyd_iters: int, backend: BackendLike = None) -> Array:
+                      lloyd_iters: int, backend: BackendLike = None,
+                      live: Optional[int] = None) -> Array:
     """Seed and update k centers on the gathered coreset; the host span
-    ``final_solve`` carries the coreset buffer's rows."""
+    ``final_solve`` carries the rows the solve sweeps.
+
+    ``live`` bounds the rows that can carry weight (the buffer's producer
+    knows it: ``coreset.gathered_live_rows``); the solve then runs on the
+    first ``live`` rows of ``cs.compact(live)``, the nonzero rows in their
+    order. That is the same weighted instance: weight-0 rows carry no
+    seeding mass and add nothing to any update's statistics (DESIGN.md
+    Sec. 7). ``None`` solves on every row."""
+    if live is not None:
+        cs = cs.compact(live)
     with jax.profiler.TraceAnnotation("final_solve",
                                       rows=cs.points.shape[0], k=k):
         centers = clustering.kmeans_pp_init(
@@ -198,7 +209,8 @@ def graph_distributed_kmeans(
                              objective=objective, lloyd_iters=lloyd_iters,
                              backend=backend, strategy=strategy)
     cs = dc.flatten()
-    centers = _solve_on_coreset(k2, cs, k, objective, lloyd_iters, backend)
+    centers = _solve_on_coreset(k2, cs, k, objective, lloyd_iters, backend,
+                                live=gathered_live_rows(cs.size, t, k))
 
     spec = strat.exchange_spec()
     ledger = flood_cost(graph, n_messages=graph.n,
@@ -301,7 +313,8 @@ def _graph_exec(key, site_points, site_mask, k, t, graph, objective,
 
     # every node holds the identical instance; solve it once (node 0's copy)
     cs = Coreset(detail.node_points[0], detail.node_weights[0])
-    centers = _solve_on_coreset(k2, cs, k, objective, lloyd_iters, backend)
+    centers = _solve_on_coreset(k2, cs, k, objective, lloyd_iters, backend,
+                                live=gathered_live_rows(cs.size, t, k))
     ledger = detail.rounds["round1"].ledger.tag("round1").add(
         detail.rounds["round2"].ledger.tag("round2"))
     return ClusteringResult(centers, cs, ledger, local_costs,
@@ -334,7 +347,8 @@ def _graph_async(key, site_points, site_mask, k, t, graph, objective,
         seed=seed, p=p, strategy=strategy)
 
     cs = Coreset(detail.node_points[0], detail.node_weights[0])
-    centers = _solve_on_coreset(k2, cs, k, objective, lloyd_iters, backend)
+    centers = _solve_on_coreset(k2, cs, k, objective, lloyd_iters, backend,
+                                live=gathered_live_rows(cs.size, t, k))
     ledger = detail.rounds["round2"].ledger.tag("round2")
     if "round1" in detail.rounds:   # single-shuffle strategies skip it
         ledger = detail.rounds["round1"].ledger.tag("round1").add(ledger)
@@ -384,7 +398,8 @@ def distributed_kmeans_tree(
                              objective=objective, lloyd_iters=lloyd_iters,
                              backend=backend, strategy=strategy)
     cs = dc.flatten()
-    centers = _solve_on_coreset(k2, cs, k, objective, lloyd_iters, backend)
+    centers = _solve_on_coreset(k2, cs, k, objective, lloyd_iters, backend,
+                                live=gathered_live_rows(cs.size, t, k))
 
     t_i = [float(x) for x in dc.t_i]
     per_node = [t_i[v] + k for v in range(tree.n)]
@@ -497,7 +512,8 @@ def _tree_exec(key, site_points, site_mask, k, t, tree, objective,
             clip_negative=False, backend=backend, strategy=strategy)
 
     cs = Coreset(root_pts.reshape(-1, d), root_w.reshape(-1))
-    centers = _solve_on_coreset(k2, cs, k, objective, lloyd_iters, backend)
+    centers = _solve_on_coreset(k2, cs, k, objective, lloyd_iters, backend,
+                                live=gathered_live_rows(cs.size, t, k))
     node_centers, r2b = tree_broadcast_exec(sched, centers,
                                             unit_points=float(k), dim=d)
     rounds = dict(rounds, round2_broadcast=r2b)

@@ -118,7 +118,7 @@ def certify_quiescence(g: Graph, plan: FaultPlan, mode: str = "full",
         from repro.core import backend as backend_mod
         from repro.core.distributed import (_solve_on_coreset,
                                             graph_distributed_kmeans)
-        from repro.core.coreset import Coreset
+        from repro.core.coreset import Coreset, gathered_live_rows
         from repro.wan.runtime import restricted_sim_coreset
         import jax
 
@@ -132,8 +132,9 @@ def certify_quiescence(g: Graph, plan: FaultPlan, mode: str = "full",
             k1, site_points, site_mask, k, t, t_buffer=t,
             objective="kmeans", lloyd_iters=8, clip_negative=False,
             backend=backend, surviving=surv)
-        oracle_centers = _solve_on_coreset(k2, Coreset(pts, w), k,
-                                           "kmeans", 8, backend)
+        oracle_centers = _solve_on_coreset(
+            k2, Coreset(pts, w), k, "kmeans", 8, backend,
+            live=gathered_live_rows(pts.shape[0], t, k))
         centers_match = (
             bool(np.array_equal(np.asarray(result.coreset.points),
                                 np.asarray(pts)))

@@ -152,5 +152,7 @@ def test_a_job_emits_each_stage_span_once_in_order(tmp_path, engine,
     rows = sp.shape[0] * sp.shape[1]
     assert args["round1"] == dict(sites=N_SITES, rows=rows, d=D, k=K)
     assert args["round2"] == dict(sites=N_SITES, rows=rows)
-    assert args["final_solve"] == dict(rows=res.coreset.points.shape[0],
-                                       k=K)
+    # the final solve sweeps the rows that can carry weight: t samples and
+    # each site's K centers, not the whole buffer
+    assert res.coreset.points.shape[0] == N_SITES * (t + K)
+    assert args["final_solve"] == dict(rows=t + N_SITES * K, k=K)

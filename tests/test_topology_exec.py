@@ -225,7 +225,7 @@ def test_graph_engine_every_node_solves_identically(site_data):
     same centers the engine reports."""
     sp, sm, k = site_data
     g = _graph("er")
-    from repro.core.coreset import Coreset
+    from repro.core.coreset import Coreset, gathered_live_rows
     from repro.core.distributed import _solve_on_coreset
     ex = graph_distributed_kmeans(KEY, sp, sm, k, t=90, graph=g,
                                   engine="exec")
@@ -233,7 +233,9 @@ def test_graph_engine_every_node_solves_identically(site_data):
     det = ex.exec_detail
     for v in range(g.n):
         cs_v = Coreset(det.node_points[v], det.node_weights[v])
-        centers_v = _solve_on_coreset(k2, cs_v, k, "kmeans", 8, None)
+        centers_v = _solve_on_coreset(
+            k2, cs_v, k, "kmeans", 8, None,
+            live=gathered_live_rows(cs_v.size, 90, k))
         np.testing.assert_array_equal(np.asarray(centers_v),
                                       np.asarray(ex.centers))
 

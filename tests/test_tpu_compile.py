@@ -1,8 +1,8 @@
-"""Compile the Pallas kernels for a described TPU v5e at the widths of the
-paper's full-scale job (``chip_smoke.py``): YearPredictionMSD sites of
-21,280 x 90 f32 rows, k=50, 100 sites per chip. Nothing runs; the chip's
-compiler refuses here what it would refuse on the chip (tiling, VMEM
-limits, device memory)."""
+"""Compile the Pallas kernels (and the final solve's compaction) for a
+described TPU v5e at the widths of the paper's full-scale job
+(``chip_smoke.py``): YearPredictionMSD sites of 21,280 x 90 f32 rows,
+k=50, 100 sites per chip. Nothing runs; the chip's compiler refuses here
+what it would refuse on the chip (tiling, VMEM limits, device memory)."""
 import os
 
 import jax
@@ -70,6 +70,24 @@ def test_kernel_compiles_for_v5e(one_chip, case):
             for s in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    ma = compiled.memory_analysis()
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+             + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    assert total < HBM_BYTES, total
+
+
+def test_final_solve_compaction_compiles_for_v5e(one_chip):
+    """The final solve's move of the coreset's live rows to the front, at
+    the job's buffer (100 sites x (t = 15,000 + k) slots, 20,000 live):
+    an index from a cumsum and one gather, no sort over the buffer."""
+    from repro.core.coreset import Coreset
+    rows, live = SITES * (15_000 + K), 15_000 + SITES * K
+    cs = Coreset(jax.ShapeDtypeStruct((rows, D), jnp.float32,
+                                      sharding=one_chip),
+                 jax.ShapeDtypeStruct((rows,), jnp.float32,
+                                      sharding=one_chip))
+    compiled = Coreset.compact.lower(cs, live).compile()
+    assert " sort(" not in compiled.as_text()
     ma = compiled.memory_analysis()
     total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
              + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
