@@ -1,9 +1,10 @@
 """Unified clustering-backend dispatch layer (DESIGN.md Sec. 8).
 
-Every hot path of the pipeline -- Algorithm 1's local solves, D^2 seeding,
-sensitivity computation, and the final coreset solve of Algorithm 2, for
-*every* registered objective (:mod:`repro.core.objective`) -- reduces to
-the same three primitive ops over a (possibly weighted) point set:
+Every hot path of the pipeline -- Algorithm 1's local solves, sensitivity
+computation, and the final coreset solve of Algorithm 2, for *every*
+registered objective (:mod:`repro.core.objective`) -- reduces to the same
+three primitive ops over a (possibly weighted) point set (D^2 seeding's
+one-center sweep is plain ``jnp``, the same on every backend):
 
 * ``min_dist_argmin(points, centers)``
     ``(n, d), (k, d) -> (min_d2 (n,) f32, argmin (n,) i32)``

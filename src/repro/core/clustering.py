@@ -6,7 +6,8 @@ coreset (Algorithm 2 Round 2). Every function supports per-point weights --
 the coreset is a *weighted* instance, possibly with negative center weights
 -- and is jit-compatible with static ``k`` and iteration counts.
 
-Every distance/statistics hot loop dispatches through the backend registry
+Every distance/statistics hot loop but the seeding's one-center sweep
+dispatches through the backend registry
 (:mod:`repro.core.backend`): ``backend`` accepts a registry name
 (``"jnp"``, ``"jnp_chunked"``, ``"pallas"``), a :class:`ClusteringBackend`
 instance, or ``None`` for the ambient default (``use_backend`` /
@@ -151,10 +152,14 @@ def kmeans_pp_init(
     padding is never selected -- its logit is -inf), trimmed objectives
     additionally zero the mass of the current top-t residual points so
     seeds avoid far-field outliers.
+
+    Seeding is backend-independent: each step updates every point's
+    distance with one fused sweep against the new center, in plain
+    ``jnp``, on every backend. ``backend`` is accepted and ignored.
     """
+    del backend
     return _kmeans_pp_init(key, points, weights, k=k,
-                           objective=objective_mod.resolve_name(objective),
-                           backend=backend_mod.resolve_name(backend))
+                           objective=objective_mod.resolve_name(objective))
 
 
 def _masked_choice(key, mass):
@@ -168,29 +173,37 @@ def _masked_choice(key, mass):
     return jnp.where(jnp.sum(mass) > 0.0, idx, 0).astype(jnp.int32)
 
 
-@functools.partial(jax.jit, static_argnames=("k", "objective", "backend"))
+def _one_center_sq_dists(points: Array, c: Array) -> Array:
+    """Exact-form f32 squared distance of every point to one center, (n,):
+    one fused read of ``points`` with no pad, matmul or argmin, and exactly
+    0 at a point equal to ``c``."""
+    return jnp.sum(jnp.square(points.astype(jnp.float32)
+                              - c.astype(jnp.float32)), axis=-1)
+
+
+@functools.partial(jax.jit, static_argnames=("k", "objective"))
 @jax.named_scope("seed")
-def _kmeans_pp_init(key, points, weights, k, objective, backend):
+def _kmeans_pp_init(key, points, weights, k, objective):
     obj = objective_mod.get_objective(objective)
-    b = backend_mod.get_backend(backend)
     n, d = points.shape
     w = jnp.ones((n,), points.dtype) if weights is None else weights
     w = jnp.maximum(w, 0.0)
 
     def dist_to(c):
-        # distance of every point to one candidate center, via the backend
-        d2 = b.min_dist_argmin(points, c[None, :])[0]
-        return obj.clamped_cost(d2)
+        return obj.clamped_cost(_one_center_sq_dists(points, c))
 
     key, k0 = jax.random.split(key)
-    first = _masked_choice(k0, w)
+    with jax.named_scope("draw"):
+        first = _masked_choice(k0, w)
     centers = jnp.zeros((k, d), points.dtype).at[0].set(points[first])
     mind = dist_to(points[first])
 
     def body(i, carry):
         centers, mind, key = carry
         key, ki = jax.random.split(key)
-        idx = _masked_choice(ki, obj.seeding(w, mind))
+        mass = obj.seeding(w, mind)
+        with jax.named_scope("draw"):
+            idx = _masked_choice(ki, mass)
         c = points[idx]
         centers = centers.at[i].set(c)
         mind = jnp.minimum(mind, dist_to(c))

@@ -1,8 +1,8 @@
 """Fused pairwise-distance + online arg-min Pallas TPU kernel.
 
-The assignment step of Lloyd's algorithm, the D^2 seeding of k-means++ and
-the sensitivity computation m_p = cost(p, B_i) of Algorithm 1 all reduce to:
-for every point, the min/argmin squared distance over k centers. The naive
+The assignment step of Lloyd's algorithm and the sensitivity computation
+m_p = cost(p, B_i) of Algorithm 1 both reduce to: for every point, the
+min/argmin squared distance over k centers. The naive
 formulation materializes an (n, k) distance matrix in HBM; this kernel tiles
 points x centers into VMEM, computes the distance tile via a single MXU
 matmul (d^2 = |p|^2 + |c|^2 - 2 p.c) and keeps a *running* min/argmin across

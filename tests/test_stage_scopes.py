@@ -84,9 +84,12 @@ def test_final_solve_programs_carry_seed_and_update():
     pts = jax.random.normal(jax.random.PRNGKey(2), (64, D))
     w = jnp.ones((64,))
     seed = _scope_paths(clustering._kmeans_pp_init.lower(
-        jax.random.PRNGKey(3), pts, w, k=K, objective="kmeans",
-        backend="jnp").compile().as_text())
+        jax.random.PRNGKey(3), pts, w, k=K,
+        objective="kmeans").compile().as_text())
     assert _has(seed, "seed") and not _has(seed, "round1")
+    # the categorical draws, nested in the seeding scope
+    assert any(p.index("seed") < p.index("draw")
+               for p in seed if "seed" in p and "draw" in p)
     c = pts[:K]
     upd = _scope_paths(clustering._lloyd.lower(
         pts, c, w, iters=2, objective="kmeans", k=K,
