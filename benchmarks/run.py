@@ -1,5 +1,5 @@
-"""Benchmark entry point -- one section per paper table/figure plus the LM
-roofline. Prints ``name,us_per_call,derived`` CSV rows; the kernels section
+"""Benchmark entry point -- one section per paper table/figure and per
+subsystem. Prints ``name,us_per_call,derived`` CSV rows; the kernels section
 additionally writes its rows to ``BENCH_kernels.json`` at the repo root
 (the CI perf-trajectory artifact).
 
@@ -16,8 +16,7 @@ import time
 from benchmarks import (bench_collectives, bench_comm_scaling,
                         bench_coreset_size, bench_faults, bench_fig2_graphs,
                         bench_fig3_trees, bench_frontier, bench_kernels,
-                        bench_roofline, bench_serve, bench_stream,
-                        bench_topologies)
+                        bench_serve, bench_stream, bench_topologies)
 from benchmarks.common import write_json_rows
 from repro.cache import enable_compilation_cache
 
@@ -30,7 +29,7 @@ def main(argv=None) -> None:
                     help="paper-scale datasets and run counts")
     ap.add_argument("--only", default="",
                     help="comma-separated subset: fig2,fig3,comm,size,"
-                         "kernels,roofline,serve,stream,topologies,faults,"
+                         "kernels,serve,stream,topologies,faults,"
                          "frontier,collectives")
     args = ap.parse_args(argv)
     enable_compilation_cache()
@@ -95,8 +94,6 @@ def main(argv=None) -> None:
         out_json = os.path.join(_REPO_ROOT, "BENCH_frontier.json")
         write_json_rows(out_json, frontier_rows)
         print(f"# wrote {out_json}", file=sys.stderr)
-    if only is None or "roofline" in only:
-        bench_roofline.run(out_rows=rows)
     print(f"# total {time.time()-t0:.1f}s, {len(rows)-1} rows",
           file=sys.stderr)
 

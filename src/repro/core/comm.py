@@ -2,9 +2,9 @@
 
 The paper measures communication in "number of points transmitted"; we keep
 that unit (``points``) and also derive bytes (``(d+1) * 4`` bytes per weighted
-point, ``4`` per scalar) so the LM-side roofline and the clustering-side
-experiments share one currency. Every algorithm in ``repro.core`` returns a
-``CommLedger`` alongside its result.
+point, ``4`` per scalar) so the analytic ledgers, the executed schedules
+and the SPMD collectives share one currency. Every algorithm in
+``repro.core`` returns a ``CommLedger`` alongside its result.
 
 Heterogeneous links add a fourth axis, ``link_cost``: cost-weighted bytes.
 Every transmission is priced by the edge it crosses -- a payload of ``b``

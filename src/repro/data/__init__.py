@@ -1,7 +1,4 @@
-from repro.data import selection, synthetic
-from repro.data.selection import Selection, embed_examples, gather_selected, select_coreset
-from repro.data.synthetic import BigramLM, paper_dataset, paper_dataset_names
+from repro.data import synthetic
+from repro.data.synthetic import paper_dataset, paper_dataset_names
 
-__all__ = ["selection", "synthetic", "Selection", "embed_examples",
-           "gather_selected", "select_coreset", "BigramLM", "paper_dataset",
-           "paper_dataset_names"]
+__all__ = ["synthetic", "paper_dataset", "paper_dataset_names"]

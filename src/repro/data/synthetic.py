@@ -1,58 +1,17 @@
 """Deterministic synthetic data.
 
-* :class:`BigramLM` -- token streams from a fixed random bigram chain over a
-  restricted vocabulary slice: a learnable distribution, so the end-to-end
-  training examples show real loss reduction.
-* :func:`paper_datasets` -- Gaussian-mixture stand-ins shape-matched to the
+* :func:`paper_dataset` -- Gaussian-mixture stand-ins shape-matched to the
   paper's evaluation datasets (the UCI files are unavailable offline; see
   DESIGN.md Sec. 7). The ``synthetic`` entry *is* the paper's own synthetic
   setup: k=5 centers ~ N(0, I_10), 20k points per center.
+* :func:`drifting_mixture_stream` / :func:`contaminated_stream` -- seeded
+  non-stationary mixture streams, the second with injected outliers.
 """
 from __future__ import annotations
 
-import dataclasses
-from typing import Dict, Iterator, Tuple
+from typing import Iterator, Tuple
 
-import jax
-import jax.numpy as jnp
 import numpy as np
-
-Array = jax.Array
-
-
-@dataclasses.dataclass
-class BigramLM:
-    """Fixed random bigram transition matrix over ``active_vocab`` ids."""
-
-    vocab_size: int
-    active_vocab: int = 256
-    seed: int = 0
-    temperature: float = 0.7
-
-    def __post_init__(self):
-        self.active_vocab = min(self.active_vocab, self.vocab_size)
-        key = jax.random.PRNGKey(self.seed)
-        self._logits = (jax.random.normal(
-            key, (self.active_vocab, self.active_vocab)) / self.temperature)
-
-    def batch(self, step: int, batch_size: int, seq_len: int
-              ) -> Dict[str, Array]:
-        """Returns {"tokens": (B, L) i32, "labels": (B, L) i32}; labels are
-        the next-token targets."""
-        key = jax.random.PRNGKey(hash(("bigram", self.seed, step)) % (2**31))
-        k0, k1 = jax.random.split(key)
-        first = jax.random.randint(k0, (batch_size,), 0, self.active_vocab)
-
-        def gen(carry, k):
-            tok = carry
-            nxt = jax.random.categorical(k, self._logits[tok], axis=-1)
-            return nxt, nxt
-
-        keys = jax.random.split(k1, seq_len)
-        _, seq = jax.lax.scan(gen, first, keys)
-        seq = jnp.concatenate([first[None], seq], axis=0).T  # (B, L+1)
-        return {"tokens": seq[:, :-1].astype(jnp.int32),
-                "labels": seq[:, 1:].astype(jnp.int32)}
 
 
 _PAPER_SHAPES = {

@@ -1,4 +1,4 @@
-from repro.roofline import hlo, report
-from repro.roofline.report import RooflineReport, build_report
+from repro.roofline import hlo
+from repro.roofline.hlo import collective_phase_analysis
 
-__all__ = ["hlo", "report", "RooflineReport", "build_report"]
+__all__ = ["hlo", "collective_phase_analysis"]

@@ -1,15 +1,14 @@
-"""Loop-aware HLO analysis for the roofline report.
+"""Loop-aware collective analysis of compiled HLO text.
 
-XLA's ``compiled.cost_analysis()`` counts while-loop bodies ONCE (verified
-empirically: a scan of length 10 reports the same flops as length 1), and our
-models run everything -- layers, microbatches, attention chunks -- under
-``lax.scan``. This module therefore parses the post-partitioning HLO text
-into a computation graph, extracts while-loop trip counts from their
-condition computations, and accumulates:
+XLA's ``compiled.cost_analysis()`` counts while-loop bodies ONCE (a scan of
+length 10 reports the same numbers as length 1), and the SPMD clustering
+path issues its ring/torus relays inside ``fori_loop`` bodies. This module
+therefore parses the post-partitioning HLO text into a computation graph,
+extracts while-loop trip counts from their condition computations, and
+accumulates, per ``jax.named_scope`` phase:
 
-  * dot FLOPs (matmul-dominated models; elementwise flops are reported
-    separately as result-element counts),
-  * per-collective link bytes with the standard algorithmic factors
+  * per-collective issue counts and link bytes with the standard
+    algorithmic factors
       all-reduce        2 (N-1)/N x bytes
       all-gather        (N-1)/N x result bytes
       reduce-scatter    (N-1) x result bytes   (= (N-1)/N x operand)
@@ -56,14 +55,6 @@ def _shape_bytes(type_str: str) -> int:
     return total
 
 
-def _shape_dims(type_str: str) -> Optional[Tuple[str, List[int]]]:
-    m = _SHAPE_RE.search(type_str)
-    if not m:
-        return None
-    dtype, dims = m.groups()
-    return dtype, [int(d) for d in dims.split(",") if d]
-
-
 @dataclasses.dataclass
 class Op:
     name: str
@@ -77,7 +68,6 @@ class Op:
 class Computation:
     name: str
     ops: List[Op]
-    symtab: Dict[str, str]        # op/param name -> result type string
 
 
 def parse_computations(hlo: str) -> Dict[str, Computation]:
@@ -85,7 +75,7 @@ def parse_computations(hlo: str) -> Dict[str, Computation]:
     cur: Optional[Computation] = None
     for line in hlo.splitlines():
         if _COMP_RE.match(line):
-            cur = Computation(_COMP_RE.match(line).group(1), [], {})
+            cur = Computation(_COMP_RE.match(line).group(1), [])
             comps[cur.name] = cur
             continue
         if cur is None:
@@ -106,13 +96,11 @@ def parse_computations(hlo: str) -> Dict[str, Computation]:
             continue
         rtype, opcode, rest = m2.groups()
         cur.ops.append(Op(name, opcode, rtype.strip(), rest, line))
-        cur.symtab[name] = rtype.strip()
     return comps
 
 
 _CALL_RE = re.compile(
     r"(?:to_apply|body|condition|calls|branch_computations)=\{?%?([\w.\-]+)")
-_OPERAND_RE = re.compile(r"%([\w.\-]+)")
 _CONST_RE = re.compile(r"constant\((\d+)\)")
 _GROUPS_BRACE_RE = re.compile(r"replica_groups=\{\{([\d,]+)\}")
 _GROUPS_IOTA_RE = re.compile(r"replica_groups=\[(\d+),(\d+)\]")
@@ -136,51 +124,6 @@ def _trip_count(cond: Computation,
     return best
 
 
-def _dot_flops(op: Op, symtab: Dict[str, str]) -> float:
-    """2 * batch * M * N * K from operand shapes + contracting/batch dims."""
-    operands = _OPERAND_RE.findall(op.rest.split(")")[0])
-    if len(operands) < 2:
-        return 0.0
-    lhs_t = symtab.get(operands[0])
-    rhs_t = symtab.get(operands[1])
-    if not lhs_t or not rhs_t:
-        return 0.0
-    lhs = _shape_dims(lhs_t)
-    rhs = _shape_dims(rhs_t)
-    if not lhs or not rhs:
-        return 0.0
-    _, ld = lhs
-    _, rd = rhs
-
-    def dims_attr(key):
-        m = re.search(key + r"=\{([\d,]*)\}", op.rest)
-        if not m or not m.group(1):
-            return []
-        return [int(x) for x in m.group(1).split(",")]
-
-    lc = dims_attr("lhs_contracting_dims")
-    lb = dims_attr("lhs_batch_dims")
-    k = 1
-    for i in lc:
-        if i < len(ld):
-            k *= ld[i]
-    b = 1
-    for i in lb:
-        if i < len(ld):
-            b *= ld[i]
-    m_dim = 1
-    for i, d in enumerate(ld):
-        if i not in lc and i not in lb:
-            m_dim *= d
-    rc = dims_attr("rhs_contracting_dims")
-    rb = dims_attr("rhs_batch_dims")
-    n_dim = 1
-    for i, d in enumerate(rd):
-        if i not in rc and i not in rb:
-            n_dim *= d
-    return 2.0 * b * m_dim * n_dim * k
-
-
 def _group_size_and_span(op: Op, pod_block: Optional[int]
                          ) -> Tuple[int, bool]:
     """(replica group size, crosses_pod). ``pod_block`` = devices per pod."""
@@ -202,9 +145,6 @@ def _group_size_and_span(op: Op, pod_block: Optional[int]
 
 @dataclasses.dataclass
 class Analysis:
-    dot_flops: float = 0.0
-    elementwise_flops: float = 0.0
-    result_bytes: float = 0.0           # sum of op result buffer bytes
     ici_collective_bytes: float = 0.0
     dcn_collective_bytes: float = 0.0
     collective_counts: Dict[str, float] = dataclasses.field(
@@ -213,9 +153,6 @@ class Analysis:
         default_factory=dict)
 
     def add(self, other: "Analysis", mult: float = 1.0):
-        self.dot_flops += other.dot_flops * mult
-        self.elementwise_flops += other.elementwise_flops * mult
-        self.result_bytes += other.result_bytes * mult
         self.ici_collective_bytes += other.ici_collective_bytes * mult
         self.dcn_collective_bytes += other.dcn_collective_bytes * mult
         for k, v in other.collective_counts.items():
@@ -226,18 +163,12 @@ class Analysis:
                 self.collective_bytes_by_kind.get(k, 0.0) + v * mult)
 
 
-_ELEMENTWISE = {
-    "add", "subtract", "multiply", "divide", "maximum", "minimum", "exp",
-    "tanh", "negate", "abs", "power", "rsqrt", "sqrt", "log", "select",
-    "compare", "and", "or", "convert", "floor", "clamp", "sign",
-}
-
 _OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
 
 
 def _collective_link(op: Op, pod_block: Optional[int]
-                     ) -> Optional[Tuple[str, float, float, bool]]:
-    """(kind, link_bytes, result_bytes, crosses_pod) for a collective op
+                     ) -> Optional[Tuple[str, float, bool]]:
+    """(kind, link_bytes, crosses_pod) for a collective op
     (including async ``*-start`` halves), else ``None``. The link factors
     are the standard algorithmic ones from the module docstring."""
     if not (op.opcode in COLLECTIVES
@@ -267,7 +198,7 @@ def _collective_link(op: Op, pod_block: Optional[int]
         link = (n - 1) / max(n, 1) * rb
     else:  # collective-permute
         link = rb
-    return kind, link, float(rb), crosses
+    return kind, link, crosses
 
 
 def _op_phase(op: Op, phases: Tuple[str, ...]) -> Optional[str]:
@@ -287,73 +218,6 @@ def _op_phase(op: Op, phases: Tuple[str, ...]) -> Optional[str]:
     return None
 
 
-def analyze(hlo: str, pod_block: Optional[int] = None,
-            entry: Optional[str] = None) -> Analysis:
-    comps = parse_computations(hlo)
-    if not comps:
-        return Analysis()
-    entry_name = entry
-    if entry_name is None:
-        m = re.search(r"ENTRY\s+%?([\w.\-]+)", hlo)
-        entry_name = m.group(1) if m else next(iter(comps))
-    cache: Dict[str, Analysis] = {}
-
-    def visit(name: str, depth: int = 0) -> Analysis:
-        if name in cache:
-            return cache[name]
-        out = Analysis()
-        comp = comps.get(name)
-        if comp is None or depth > 60:
-            return out
-        cache[name] = out  # provisional (cycles cannot occur in HLO)
-        for op in comp.ops:
-            rb = _shape_bytes(op.result_type)
-            if op.opcode == "while":
-                called = _CALL_RE.findall(op.rest)
-                body = None
-                cond = None
-                mb = re.search(r"body=%?([\w.\-]+)", op.rest)
-                mc = re.search(r"condition=%?([\w.\-]+)", op.rest)
-                body = mb.group(1) if mb else (called[0] if called else None)
-                cond = mc.group(1) if mc else None
-                trips = (_trip_count(comps[cond], comps)
-                         if cond in comps else 1)
-                if body:
-                    out.add(visit(body, depth + 1), mult=trips)
-            elif op.opcode in ("fusion", "call", "conditional", "map",
-                               "reduce", "reduce-window", "sort", "scatter",
-                               "select-and-scatter", "custom-call",
-                               "async-start"):
-                for called in _CALL_RE.findall(op.rest):
-                    out.add(visit(called, depth + 1))
-                out.result_bytes += rb
-                if op.opcode == "reduce":
-                    out.elementwise_flops += rb / 4.0
-            elif op.opcode == "dot":
-                out.dot_flops += _dot_flops(op, comp.symtab)
-                out.result_bytes += rb
-            elif _collective_link(op, pod_block) is not None:
-                kind, link, rb, crosses = _collective_link(op, pod_block)
-                if crosses:
-                    out.dcn_collective_bytes += link
-                else:
-                    out.ici_collective_bytes += link
-                out.collective_counts[kind] = (
-                    out.collective_counts.get(kind, 0.0) + 1)
-                out.collective_bytes_by_kind[kind] = (
-                    out.collective_bytes_by_kind.get(kind, 0.0) + link)
-                out.result_bytes += rb
-            else:
-                if op.opcode in _ELEMENTWISE:
-                    out.elementwise_flops += rb / 4.0
-                out.result_bytes += rb
-        return out
-
-    res = visit(entry_name)
-    cache.pop(entry_name, None)
-    return res
-
-
 def collective_phase_analysis(
     hlo: str,
     phases: Tuple[str, ...] = ("round1", "round2"),
@@ -363,8 +227,7 @@ def collective_phase_analysis(
     """Per-phase collective ledger: loop-aware collective op counts and
     link bytes, attributed to the ``jax.named_scope`` phase each collective
     was traced under (``_op_phase``). Collectives outside every named phase
-    land in ``"other"``. Only the collective fields of each
-    :class:`Analysis` are populated.
+    land in ``"other"``.
 
     Counts are *sequential issue* counts: a ``ppermute`` inside a
     ``fori_loop`` while-body counts once per trip, so
@@ -410,7 +273,7 @@ def collective_phase_analysis(
                 continue
             link = _collective_link(op, pod_block)
             if link is not None:
-                kind, bytes_, rb, crosses = link
+                kind, bytes_, crosses = link
                 phase = _op_phase(op, phases) or "other"
                 a = acc.setdefault(phase, Analysis())
                 a.collective_counts[kind] = (

@@ -1,11 +1,8 @@
-"""Serving layer: the LM slot engine (`engine`) and the multi-tenant
-coreset-query serving engine (`cluster`, DESIGN.md Sec. 13)."""
+"""Serving layer: the multi-tenant coreset-query serving engine
+(`cluster`, DESIGN.md Sec. 13)."""
 
 from repro.serve.cluster import (ClusterServeEngine, EngineStats,
                                  QueryTicket, StaticCenters)
-from repro.serve.engine import Engine, Request, generate, make_serve_steps
 
-__all__ = [
-    "ClusterServeEngine", "EngineStats", "QueryTicket", "StaticCenters",
-    "Engine", "Request", "generate", "make_serve_steps",
-]
+__all__ = ["ClusterServeEngine", "EngineStats", "QueryTicket",
+           "StaticCenters"]

@@ -4,11 +4,9 @@ The paper's deployment story makes nearest-center *queries* the hot path: a
 small coreset summary stands in for the full data, so a serving tier pays
 for assignment dispatches, not solves. :class:`ClusterServeEngine` serves
 many concurrent (stream, k, model) tenants by fusing their query traffic
-into single device dispatches -- the slot-machinery idea of
-:class:`repro.serve.engine.Engine` (admit requests, batch them into one
-jit call per step, free capacity as they finish) re-built around the
-stacked-center assignment primitive
-:func:`repro.core.backend.query_assignments_batched`:
+into single device dispatches -- admit requests, batch them into one jit
+call per step, free capacity as they finish -- around the stacked-center
+assignment primitive :func:`repro.core.backend.query_assignments_batched`:
 
 * **admission queue + continuous batching**: ``enqueue(tenant, points)``
   is non-blocking and returns a :class:`QueryTicket`; each ``step()``

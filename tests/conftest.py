@@ -1,7 +1,7 @@
 import os
 
 # Tests run on the single real CPU device; SPMD tests spawn subprocesses with
-# their own XLA_FLAGS (the 512-device dry run must NOT leak in here).
+# their own XLA_FLAGS (a forced multi-device host must NOT leak in here).
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import gc

@@ -411,3 +411,23 @@ def test_collective_phase_analysis_loops_and_scopes():
     ph2 = collective_phase_analysis(_PHASED_HLO, phases=("round2",))
     assert ph2["round2"].collective_counts == {"all-gather": 1.0}
     assert ph2["other"].collective_counts.get("collective-permute") == 3.0
+
+
+def test_collective_link_factors():
+    """Link bytes per collective kind carry the standard algorithmic
+    factors of ``roofline/hlo.py``'s docstring (N = 4 replicas)."""
+    txt = """
+HloModule test
+
+ENTRY %main (x0: f32[64,64]) -> f32[64,64] {
+  %x0 = f32[64,64]{1,0} parameter(0)
+  %ar = f32[64,64]{1,0} all-reduce(%x0), replica_groups={{0,1,2,3}}, to_apply=%add
+  %ag = f32[64,64]{1,0} all-gather(%ar), replica_groups={{0,1,2,3}}, dimensions={0}
+  ROOT %cp = f32[64,64]{1,0} collective-permute(%ag), source_target_pairs={{0,1}}
+}
+"""
+    by_kind = collective_phase_analysis(txt)["other"].collective_bytes_by_kind
+    b = 64 * 64 * 4
+    assert abs(by_kind["all-reduce"] - 2 * 3 / 4 * b) < 1
+    assert abs(by_kind["all-gather"] - 3 / 4 * b) < 1
+    assert abs(by_kind["collective-permute"] - b) < 1

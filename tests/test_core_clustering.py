@@ -6,6 +6,7 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.core import clustering
+from repro.data import paper_dataset, paper_dataset_names
 
 KEY = jax.random.PRNGKey(0)
 
@@ -139,3 +140,10 @@ def test_empty_cluster_keeps_previous_center():
     centers, _ = clustering.lloyd(pts, far, iters=2)
     c = np.asarray(centers)
     np.testing.assert_allclose(c[2], [100, 100], atol=1e-6)  # untouched
+
+
+def test_paper_dataset_shapes():
+    for name in paper_dataset_names():
+        pts, k = paper_dataset(name, scale=0.02)
+        assert pts.ndim == 2 and np.isfinite(pts).all()
+        assert k >= 5
